@@ -86,9 +86,9 @@ out = {
                  "lease_steps": mgr.spec.lease_steps,
                  "chunk_bytes": mgr.spec.chunk_bytes,
                  "lookup_impl": spec.model.embedding.lookup_impl},
-    # the decode path is XLA-native at the model compute dtype; wall-clock
-    # columns are CPU-container numbers and explicitly non-headline
-    "mode": "native", "dtype": spec.model.compute_dtype,
+    # the worker runs on forced CPU host devices; wall-clock columns
+    # are CPU numbers and explicitly non-headline
+    "mode": "cpu", "dtype": spec.model.compute_dtype,
     "topology": {"before": rep.n_before, "after": rep.n_after},
     "steps_lost": rep.steps_lost,
     "detected_at_step": rep.detected_at_step,
@@ -110,6 +110,8 @@ def run():
     # kill/transfer/rescale path runs in seconds
     total, kill_at = (14, 10) if steps(14) == 14 else (4, 1)
     env = dict(os.environ)
+    # a CPU rehearsal of the mesh by design: the child never takes the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = str(ROOT / "src") + (
         ":" + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")

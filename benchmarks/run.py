@@ -57,6 +57,9 @@ def main() -> None:
                          "measurement)")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.smoke:
         from benchmarks import common
         common.SMOKE = True

@@ -101,8 +101,8 @@ def run(n_shards, impl=None):
     out = {"n_shards": n_shards,
            "lookup_impl": sp.model.embedding.lookup_impl,
            # every BENCH entry carries mode + dtype (tools/ci.sh gate);
-           # the sharded lookups are XLA-native at the model compute dtype
-           "mode": "native", "dtype": sp.model.compute_dtype,
+           # the worker runs on forced CPU host devices
+           "mode": "cpu", "dtype": sp.model.compute_dtype,
            "step_us": per_step, "losses": losses,
            "frontier_rows_total": rows_total,
            "frontier_rows_per_device": rows_total // n_shards,
@@ -128,6 +128,8 @@ print("BENCH_JSON:" + json.dumps(out))
 def run():
     n_steps = steps(12)
     env = dict(os.environ)
+    # a CPU rehearsal of the mesh by design: the child never takes the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = str(ROOT / "src") + (
         ":" + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")

@@ -16,6 +16,7 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 import numpy as np
 
 from repro.configs.paper_gnn import paper_gnn_config
+from repro.compile_cache import enable_compile_cache
 from repro.core.memory import memory_breakdown, MiB
 from repro.graph.runtime import GraphRuntime, GraphSource, RuntimeSpec
 from repro.nn.module import param_bytes
@@ -77,4 +78,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -10,6 +10,7 @@ import numpy as np
 
 from benchmarks.common import kmeans, nmi
 from benchmarks.fig1_reconstruction import _train_decoder_on_reconstruction
+from repro.compile_cache import enable_compile_cache
 from repro.core import lsh
 from repro.core.embedding import decode_all
 from repro.graph.generate import clustered_embeddings
@@ -36,4 +37,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -18,6 +18,7 @@ import time
 import numpy as np
 
 from repro.configs.paper_gnn import paper_gnn_config
+from repro.compile_cache import enable_compile_cache
 from repro.graph.runtime import GraphRuntime, GraphSource, RuntimeSpec
 from repro.optim import AdamWConfig
 from repro.serving import Engine
@@ -72,4 +73,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

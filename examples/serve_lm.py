@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, reduced
+from repro.compile_cache import enable_compile_cache
 from repro.models import init_lm
 from repro.serving import DecodeEngine
 
@@ -47,4 +48,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

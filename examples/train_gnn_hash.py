@@ -19,6 +19,7 @@ import argparse
 import time
 
 from repro.configs.paper_gnn import paper_gnn_config
+from repro.compile_cache import enable_compile_cache
 from repro.graph.runtime import GraphRuntime, GraphSource, RuntimeSpec
 from repro.optim import AdamWConfig
 
@@ -82,4 +83,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

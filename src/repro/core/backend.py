@@ -266,9 +266,11 @@ class OnehotBackend(DecodeBackend):
         B = codes.shape[0]
         iota_c = jax.lax.broadcasted_iota(jnp.int32, (1, 1, c), 2)
         onehot = (codes[:, :, None] == iota_c).astype(codebooks.dtype)
+        # HIGHEST: a TPU's default f32 matmul is one bf16 pass
         out = jax.lax.dot_general(
             onehot.reshape(B, m * c), codebooks.reshape(m * c, d_c),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
         if w0 is not None:
             out = out * w0.astype(jnp.float32)[None, :]
         return out
@@ -370,8 +372,6 @@ def _sharded_decode(base: DecodeBackend, mesh, axis: str,
     backend understands (dense ``(m, c, d_c)``, or the ``tt`` core pair)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.parallel.sharding import shard_map
-
     cb_specs = _replicated_specs(codebooks)
 
     @jax.custom_vjp
@@ -379,7 +379,7 @@ def _sharded_decode(base: DecodeBackend, mesh, axis: str,
         def local(codes_l, cb_, w0_):
             out_l = base.decode(codes_l, cb_, w0_)
             return jax.lax.all_gather(out_l, axis, axis=0, tiled=True)
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(axis, None), cb_specs, P(None)),
             out_specs=P(None, None), check_vma=False)(codes, cb, w0)
@@ -398,7 +398,7 @@ def _sharded_decode(base: DecodeBackend, mesh, axis: str,
             gw0 = jax.lax.psum(gw0.astype(jnp.float32), axis).astype(w0_.dtype)
             return gcb, gw0
 
-        gcb, gw0 = shard_map(
+        gcb, gw0 = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(axis, None), P(axis, None), cb_specs, P(None)),
             out_specs=(cb_specs, P(None)),
@@ -523,7 +523,7 @@ def _owner_decode(base: DecodeBackend, mesh, axis: str,
     codebook gradient; no duplicate row is ever double-counted)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.parallel.sharding import all_to_all, shard_map
+    from repro.parallel.sharding import all_to_all
 
     n = int(plan.req_rows.shape[0])
     oc = int(plan.req_rows.shape[2])
@@ -548,7 +548,7 @@ def _owner_decode(base: DecodeBackend, mesh, axis: str,
             out_l = jnp.zeros((cap, d), dec.dtype).at[rr.reshape(-1)].set(
                 back.reshape(-1, d), mode="drop")           # sentinel cap drops
             return jax.lax.all_gather(out_l, axis, axis=0, tiled=True)
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(axis, None),) + plan_specs + (cb_specs, P(None)),
             out_specs=P(None, None), check_vma=False)(
@@ -580,7 +580,7 @@ def _owner_decode(base: DecodeBackend, mesh, axis: str,
             gw0 = jax.lax.psum(gw0.astype(jnp.float32), axis).astype(w0_.dtype)
             return gcb, gw0
 
-        gcb, gw0 = shard_map(
+        gcb, gw0 = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(axis, None),) + plan_specs
             + (P(None, None), cb_specs, P(None)),

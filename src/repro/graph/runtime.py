@@ -165,7 +165,8 @@ class RuntimeSpec:
     # elastic training knobs (repro.elastic); None = defaults when an
     # ElasticManager drives the run, irrelevant otherwise
     elastic: Optional[ElasticSpec] = None
-    # pallas interpret mode; None resolves to "not on a TPU runtime"
+    # pallas interpret mode; None resolves to "not on a TPU runtime",
+    # and True on a TPU runtime is an error
     interpret: Optional[bool] = None
 
     # -- ergonomics ------------------------------------------------------
@@ -283,8 +284,13 @@ class GraphRuntime:
         self.adj = adj
         self.labels = np.asarray(labels)
         self.cfg = cfg
+        on_tpu = jax.default_backend() == "tpu"
+        if spec.interpret and on_tpu:
+            raise ValueError(
+                "RuntimeSpec.interpret=True on a TPU runtime would run the "
+                "Pallas kernels in the interpreter; leave it None")
         self.interpret = (spec.interpret if spec.interpret is not None
-                          else jax.default_backend() != "tpu")
+                          else not on_tpu)
         self.fullgraph = cfg.model in FULLGRAPH_MODELS
 
         # -- codes + state (pure functions of the spec seeds) -------------
@@ -335,8 +341,13 @@ class GraphRuntime:
         if spec.n_shards > 1:
             from repro.parallel.policy import make_frontier_placement
             from repro.parallel.sharding import data_mesh
+            from jax.sharding import NamedSharding, PartitionSpec
             self.mesh = data_mesh(spec.n_shards)
             self.place = make_frontier_placement(self.mesh)
+            # the step returns the state replicated over the mesh; placing
+            # it so up front keeps the second step from compiling again
+            self.state = jax.device_put(
+                self.state, NamedSharding(self.mesh, PartitionSpec()))
 
         # -- sampler + batch source ----------------------------------------
         if self.fullgraph:

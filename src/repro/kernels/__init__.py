@@ -8,12 +8,3 @@ Each package: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd wrapper,
 custom VJP, oracle fallback), ref.py (pure-jnp oracle).  Kernels validate in
 interpret mode on CPU; TPU is the deployment target.
 """
-
-import jax.experimental.pallas.tpu as _pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams and TPUMemorySpace ->
-# MemorySpace (~0.5); support both spellings.
-TPUCompilerParams = getattr(_pltpu, "CompilerParams", None) or \
-    getattr(_pltpu, "TPUCompilerParams")
-TPUMemorySpace = getattr(_pltpu, "MemorySpace", None) or \
-    getattr(_pltpu, "TPUMemorySpace")

@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import TPUCompilerParams, TPUMemorySpace
-
 NEG_INF = -1e30
 
 
@@ -116,11 +114,11 @@ def flash_attention_bhsd(
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
         scratch_shapes=[
-            TPUMemorySpace.VMEM((block_q,), jnp.float32),
-            TPUMemorySpace.VMEM((block_q,), jnp.float32),
-            TPUMemorySpace.VMEM((block_q, D), jnp.float32),
+            pltpu.MemorySpace.VMEM((block_q,), jnp.float32),
+            pltpu.MemorySpace.VMEM((block_q,), jnp.float32),
+            pltpu.MemorySpace.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

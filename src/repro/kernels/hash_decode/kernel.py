@@ -16,7 +16,10 @@ traffic drops 4x (int8 values + a (m, c) f32 scale table that is ~d_c/4x
 smaller than the values).
 
 Accumulation is always f32 (``preferred_element_type``) regardless of the
-codebook storage dtype — the MixedPrecisionPolicy's ``reduce_dtype``.
+codebook storage dtype — the MixedPrecisionPolicy's ``reduce_dtype``.  The
+dot states ``precision=HIGHEST``: on a TPU the default f32 matmul is one
+bf16 pass, which rounds f32 codebooks to bf16 (2.0e-3 of the output scale
+on a v5e, against 9.6e-8 at HIGHEST; docs/decode_backends.md).
 
 Grid: (B / block_b, d_c / block_d); both parallel.
 VMEM per step (defaults block_b=256, block_d=256, c=256, m=16, f32):
@@ -36,8 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import TPUCompilerParams
-
 
 def _decode_body(codes_ref, cb_ref, w0_ref, scales_ref, o_ref, *, c: int, m: int):
     codes = codes_ref[...]                       # (bB, m) int32
@@ -54,6 +55,7 @@ def _decode_body(codes_ref, cb_ref, w0_ref, scales_ref, o_ref, *, c: int, m: int
         panel = cb_ref[j * c: (j + 1) * c, :].astype(jnp.float32)
         acc += jax.lax.dot_general(
             onehot, panel, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
     if w0_ref is not None:
@@ -113,7 +115,7 @@ def hash_decode_fwd(
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_b, block_d), lambda i, j: (i, j)),
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

@@ -1,7 +1,7 @@
 """jit'd wrapper for the hash-decode kernel with custom VJP.
 
-Forward runs the Pallas kernel (or the jnp oracle when ``use_kernel=False``
-/ unaligned shapes); backward is expressed in XLA:
+Forward runs the Pallas kernel (or the jnp oracle when ``use_kernel=False``,
+or for unaligned shapes off the TPU); backward is expressed in XLA:
     d_codebooks[j, code, :] += g ⊙ w0       (scatter-add == onehotᵀ @ g)
     d_w0 = Σ_b g ⊙ codebook_sum             (recomputed, not saved)
 Codes are integers — no gradient flows to them.
@@ -36,6 +36,12 @@ _LANE = 128
 # (shape, reason), so a new fallback cause is never silenced by an earlier,
 # unrelated one.  Tests reset via ``reset_fallback_warnings()``.
 _warned_fallback: set = set()
+
+# The backward one-hot contractions state f32 precision: a TPU's default
+# f32 matmul is one bf16 pass, which rounds the output cotangent to bf16
+# (1.7e-3 of the codebook gradient's scale on a v5e, vs the exact
+# scatter-add of the gather oracle).
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def reset_fallback_warnings() -> None:
@@ -128,10 +134,13 @@ def _bwd(block_b, block_d, interpret, use_kernel, res, g):
     g = g.astype(jnp.float32)
     gw = g * w0.astype(jnp.float32)[None, :] if w0 is not None else g
     onehot = (codes[:, :, None] == jnp.arange(c)[None, None, :]).astype(jnp.float32)
-    d_cb = jnp.einsum("bmc,bd->mcd", onehot, gw).astype(codebooks.dtype)
+    d_cb = jnp.einsum("bmc,bd->mcd", onehot, gw,
+                      precision=_HIGHEST).astype(codebooks.dtype)
     if w0 is not None:
-        summed = jnp.einsum("bmc,mcd->bd", onehot, codebooks.astype(jnp.float32))
-        d_w0 = jnp.einsum("bd,bd->d", g, summed).astype(w0.dtype)
+        summed = jnp.einsum("bmc,mcd->bd", onehot,
+                            codebooks.astype(jnp.float32), precision=_HIGHEST)
+        d_w0 = jnp.einsum("bd,bd->d", g, summed,
+                          precision=_HIGHEST).astype(w0.dtype)
     else:
         d_w0 = None
     return None, d_cb, d_w0
@@ -164,13 +173,15 @@ def _bwd_int8(block_b, block_d, interpret, use_kernel, res, g):
     onehot = (codes[:, :, None] == jnp.arange(c)[None, None, :]).astype(jnp.float32)
     # straight-through to the float masters: the codebook cotangent never
     # reads codebook VALUES, so it is identical to the unquantized backward
-    d_cb = jnp.einsum("bmc,bd->mcd", onehot, gw).astype(codebooks.dtype)
+    d_cb = jnp.einsum("bmc,bd->mcd", onehot, gw,
+                      precision=_HIGHEST).astype(codebooks.dtype)
     if w0 is not None:
         # d_w0 linearizes through the decoded values — use what the forward
         # actually decoded (the dequantized codebooks), not the masters
         deq = dequantize_codebooks(*quantize_codebooks(codebooks))
-        summed = jnp.einsum("bmc,mcd->bd", onehot, deq)
-        d_w0 = jnp.einsum("bd,bd->d", g, summed).astype(w0.dtype)
+        summed = jnp.einsum("bmc,mcd->bd", onehot, deq, precision=_HIGHEST)
+        d_w0 = jnp.einsum("bd,bd->d", g, summed,
+                          precision=_HIGHEST).astype(w0.dtype)
     else:
         d_w0 = None
     return None, d_cb, d_w0
@@ -196,9 +207,9 @@ def hash_decode(
     dequant fused into the kernel; gradients flow straight-through to the
     float masters (module docstring).
 
-    Unaligned shapes fall back to the jnp reference path with a one-time
-    warning per (shape, reason); callers that want the kernel
-    unconditionally should pad to block multiples first
+    Unaligned shapes raise on a TPU runtime; elsewhere (interpret mode on
+    the CPU) they fall back to the jnp reference path with a one-time
+    warning per (shape, reason).  Callers pad to block multiples first
     (``core.backend.PallasBackend`` does exactly that)."""
     if quantize not in ("none", "int8"):
         raise ValueError(f"quantize={quantize!r} not supported "
@@ -211,6 +222,12 @@ def hash_decode(
                                     quantized=(quantize == "int8"))
         if reasons:
             reason = "+".join(reasons)
+            if jax.default_backend() == "tpu":
+                raise ValueError(
+                    f"hash_decode: shapes B={B}, d_c={d_c} not tileable with "
+                    f"blocks ({block_b}, {block_d}) [{reason}]; pad inputs "
+                    f"(repro.core.backend.PallasBackend does) or pass "
+                    f"use_kernel=False for the reference path")
             key = (B, d_c, reason)
             if key not in _warned_fallback:
                 _warned_fallback.add(key)

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import TPUCompilerParams, TPUMemorySpace
-
 
 def _encode_body(a_ref, v_ref, t_ref, o_ref, acc_ref, *, n_dblocks: int):
     jd = pl.program_id(1)
@@ -76,8 +74,8 @@ def lsh_encode_word(
             pl.BlockSpec((1, w), lambda i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
-        scratch_shapes=[TPUMemorySpace.VMEM((block_n, w), jnp.float32)],
-        compiler_params=TPUCompilerParams(
+        scratch_shapes=[pltpu.MemorySpace.VMEM((block_n, w), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

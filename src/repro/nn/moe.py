@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.nn import module as nn
-from repro.parallel.sharding import current_mesh, current_rules, logical, shard_map
+from repro.parallel.sharding import current_mesh, current_rules, logical
 
 Array = jnp.ndarray
 
@@ -244,7 +244,7 @@ def moe_ffn_ep(params, x: Array, cfg: MoEConfig) -> Array:
 
     fn = partial(_ep_local_ffn, cfg=cfg, e_local=e_local,
                  capacity=capacity, axis_name=axis_name)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(tokens_spec, None), P(tokens_spec, None),
                   P(tokens_spec, None),

@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.sharding import shard_map
 
 Array = jnp.ndarray
 
@@ -91,7 +90,7 @@ def gpipe(stage_fn: Callable, stage_params, microbatches: Array,
 
     fn = partial(_pipeline_shard, stage_fn=stage_fn, axis=axis,
                  n_stages=n_stages)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(strip_stage(stage_params), P()),
         out_specs=P(),

@@ -20,19 +20,7 @@ import threading
 from typing import Dict, Optional, Sequence, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# shard_map graduated from jax.experimental in ~0.5 and renamed its
-# replication-check kwarg check_rep -> check_vma; support both homes.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - old-jax fallback
-    from jax.experimental.shard_map import shard_map as _shard_map_experimental
-
-    def shard_map(f, *, check_vma=None, **kwargs):
-        if check_vma is not None:
-            kwargs["check_rep"] = check_vma
-        return _shard_map_experimental(f, **kwargs)
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 MeshAxes = Union[None, str, Tuple[str, ...]]
 
@@ -182,8 +170,7 @@ def all_to_all(x, axis: str, split_axis: int = 0, concat_axis: int = 0):
     splits ``x``'s ``split_axis`` into one block per shard, sends block *j*
     to shard *j*, and concatenates the received blocks in shard order along
     ``concat_axis`` — the owner-computes exchange primitive (requests out,
-    embeddings back).  Route new collective code through this spelling, not
-    raw ``jax.lax`` (same policy as ``shard_map``/``make_mesh`` above)."""
+    embeddings back)."""
     return jax.lax.all_to_all(x, axis, split_axis, concat_axis, tiled=True)
 
 
@@ -207,9 +194,4 @@ def data_mesh(n_shards: int, devices: Optional[Sequence] = None) -> Optional[Mes
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    # jax.sharding.AxisType landed after 0.4.x; older versions default to
-    # auto axes, which is exactly what we ask for on newer ones.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

@@ -63,6 +63,16 @@ def test_hash_decode_unaligned_falls_back():
                                rtol=1e-5, atol=1e-5)
 
 
+def test_hash_decode_unaligned_raises_on_tpu(monkeypatch):
+    """On a TPU an untileable shape is an error, never a quiet fall back
+    to the reference path."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    codes = jnp.zeros((100, 8), jnp.int32)
+    cb = jnp.zeros((8, 16, 96))
+    with pytest.raises(ValueError, match="not tileable"):
+        hash_decode(codes, cb, None)
+
+
 @pytest.mark.parametrize("quantize", ["none", "int8"])
 def test_hash_decode_unaligned_backward(quantize):
     """The fallback path must keep the custom VJP: unaligned shapes

@@ -143,7 +143,7 @@ def test_gradient_compression_int8():
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from repro.parallel.sharding import make_mesh, shard_map
+        from repro.parallel.sharding import make_mesh
         from repro.optim.compress import psum_compressed, compress_gradients_int8, decompress_gradients_int8
 
         g = jax.random.normal(jax.random.PRNGKey(0), (1024,))
@@ -156,7 +156,7 @@ def test_gradient_compression_int8():
         gs = jax.random.normal(jax.random.PRNGKey(1), (8, 256))
         def worker(g, r):
             return psum_compressed(g, 'data', r)
-        out, res = jax.jit(shard_map(worker, mesh=mesh,
+        out, res = jax.jit(jax.shard_map(worker, mesh=mesh,
             in_specs=(P('data', None), P('data', None)),
             out_specs=(P('data', None), P('data', None)), check_vma=False))(
             gs[:, None, :].reshape(8, 256) * 0 + gs, jnp.zeros((8, 256)))

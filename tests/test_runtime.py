@@ -98,16 +98,33 @@ def test_prefetch_is_a_knob_not_a_code_path(graph):
         pf.close()
 
 
+# Later steps of two decode backends agree to f32 rounding, not bitwise: the
+# gather VJP is a scatter-add and the pallas VJP a one-hot einsum, so their
+# codebook gradients round differently, and 4 Adam updates carry that into
+# the loss (observed gap ~3e-7 relative at step 4).
+STEP_RTOL_F32 = 1e-5
+
+
+def test_interpret_true_on_tpu_is_an_error(graph, monkeypatch):
+    """Interpret mode on a TPU runtime would be a silent slowdown."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="interpret=True on a TPU"):
+        GraphRuntime.from_spec(_spec(interpret=True), graph=graph)
+
+
 def test_cached_pallas_is_a_spec_field_change(graph):
-    """1-shard default → cached-pallas decode is a ``with_updates`` call;
-    pallas forward is bitwise the gather oracle (PR 2) and staleness-0
-    caching is bit-exact, so the 5-step trajectory must not move."""
+    """1-shard gather → cached-pallas decode is a ``with_updates`` call.
+    The pallas forward is bitwise the gather oracle and staleness-0 caching
+    is bit-exact, so the step-0 loss is bitwise equal; the later steps stay
+    within ``STEP_RTOL_F32``."""
     base = GraphRuntime.from_spec(_spec(), graph=graph)
     cached = GraphRuntime.from_spec(
         _spec(lookup_impl="pallas", cache_capacity=2048, cache_staleness=0),
         graph=graph)
     try:
-        assert base.train(5).losses == cached.train(5).losses
+        want, got = base.train(5).losses, cached.train(5).losses
+        assert got[0] == want[0]                # bitwise, not approx
+        np.testing.assert_allclose(got, want, rtol=STEP_RTOL_F32, atol=0)
     finally:
         base.close()
         cached.close()

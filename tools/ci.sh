@@ -189,7 +189,7 @@ for name in ("BENCH_kernels.json", "BENCH_decode.json", "BENCH_shard.json",
         entries = [r for r in doc.get("runs", {}).values()
                    if isinstance(r, dict)]
     for e in entries:
-        assert e.get("mode") in ("native", "interpret"), (name, e)
+        assert e.get("mode") in ("native", "interpret", "cpu"), (name, e)
         assert isinstance(e.get("dtype"), str) and e["dtype"], (name, e)
         checked += 1
 print(f"bench artifact gate OK ({checked} entries carry mode+dtype)")
@@ -249,7 +249,7 @@ doc = json.loads(Path("BENCH_elastic.json").read_text())
 for key in ("steps_lost", "detected_at_step", "payload_bytes",
             "bytes_transferred", "chunks", "retransmits"):
     assert isinstance(doc.get(key), int), (key, doc.get(key))
-assert doc.get("mode") in ("native", "interpret"), doc.get("mode")
+assert doc.get("mode") in ("native", "interpret", "cpu"), doc.get("mode")
 assert isinstance(doc.get("dtype"), str) and doc["dtype"], doc.get("dtype")
 topo = doc.get("topology")
 assert isinstance(topo, dict) and {"before", "after"} <= set(topo), topo
