@@ -234,14 +234,17 @@ def apply_decoder(
 
     be = backend if backend is not None else get_backend(
         cfg.lookup_impl, interpret=interpret, policy=policy)
-    if plan is not None and hasattr(be, "decode_frontier"):
-        h = be.decode_frontier(codes2d, cb, w0, plan=plan).astype(dtype)
-    else:
-        h = be.decode(codes2d, cb, w0).astype(dtype)
+    # named scopes label the device ops (and their transposes) in a trace
+    with jax.named_scope("decode"):
+        if plan is not None and hasattr(be, "decode_frontier"):
+            h = be.decode_frontier(codes2d, cb, w0, plan=plan).astype(dtype)
+        else:
+            h = be.decode(codes2d, cb, w0).astype(dtype)
 
     mlp = params["mlp"]
-    for i in range(cfg.n_layers):
-        h = h @ mlp[f"w{i}"].astype(dtype) + mlp[f"b{i}"].astype(dtype)
-        if i < cfg.n_layers - 1:
-            h = jax.nn.relu(h)
+    with jax.named_scope("decoder_mlp"):
+        for i in range(cfg.n_layers):
+            h = h @ mlp[f"w{i}"].astype(dtype) + mlp[f"b{i}"].astype(dtype)
+            if i < cfg.n_layers - 1:
+                h = jax.nn.relu(h)
     return h.reshape(*lead, cfg.d_e)

@@ -44,6 +44,7 @@ from typing import Any, Dict, Optional, Sequence, Union
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import GNNConfig
 from repro.graph.csr import CSRMatrix
@@ -514,7 +515,10 @@ class PrefetchIterator:
     Per-stage producer wall-clock is accumulated and exposed via
     ``stats()`` (``sample_us`` / ``code_gather_us`` / ``put_us`` +
     ``transferred_code_bytes``) — the honest axis for judging whether the
-    host gather hides behind the device step.
+    host gather hides behind the device step.  Each stage is also a
+    profiler span (``repro.producer.sample`` / ``.code_gather`` / ``.put``)
+    around the same clock reads, so a trace places that time on the
+    device's clock.
 
     Resume semantics: each queue item carries the source state captured
     *after* producing that batch; ``state_dict()`` returns the state of the
@@ -572,26 +576,31 @@ class PrefetchIterator:
         stop, q = self._stop, self._q
         try:
             while not stop.is_set():
-                t0 = _time.perf_counter()
-                with self._lock:
-                    if stop.is_set():
-                        return
-                    batch = self.source.next_batch()
-                    state = self._snapshot()
-                t1 = _time.perf_counter()
+                # each span opens and closes with the clock reads stats()
+                # sums, so the trace and the counters time the same work
+                with TraceAnnotation("repro.producer.sample"):
+                    t0 = _time.perf_counter()
+                    with self._lock:
+                        if stop.is_set():
+                            return
+                        batch = self.source.next_batch()
+                        state = self._snapshot()
+                    t1 = _time.perf_counter()
                 if self._code_gather is not None:
-                    batch = self._code_gather(batch)
-                    self._transferred_code_bytes += self._code_bytes(batch)
+                    with TraceAnnotation("repro.producer.code_gather"):
+                        batch = self._code_gather(batch)
+                        self._transferred_code_bytes += self._code_bytes(batch)
                 t2 = _time.perf_counter()
-                if callable(self._device):
-                    batch = self._device(batch)
-                else:
-                    batch = jax.device_put(batch, self._device)
-                # block here, in the producer: the H2D copy of batch k+1
-                # completes while the consumer computes batch k (the actual
-                # double-buffering), and put_us measures the real transfer
-                jax.block_until_ready(batch)
-                t3 = _time.perf_counter()
+                with TraceAnnotation("repro.producer.put"):
+                    if callable(self._device):
+                        batch = self._device(batch)
+                    else:
+                        batch = jax.device_put(batch, self._device)
+                    # block here, in the producer: the H2D copy of batch k+1
+                    # completes while the consumer computes batch k (the actual
+                    # double-buffering), and put_us measures the real transfer
+                    jax.block_until_ready(batch)
+                    t3 = _time.perf_counter()
                 self._sample_us += (t1 - t0) * 1e6
                 self._code_gather_us += (t2 - t1) * 1e6
                 self._put_us += (t3 - t2) * 1e6

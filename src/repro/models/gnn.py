@@ -79,6 +79,7 @@ def init_gnn(key, cfg: GNNConfig, codes: Optional[Array] = None, aux=None) -> nn
 # GraphSAGE (minibatched, Figure 4)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("sage")
 def _sage_combine(params, h0: Array, h1: Array, h2: Array) -> Array:
     """Figure-4 aggregate/concat/linear stack on decoded level features
     h0 (B, de), h1 (B, f1, de), h2 (B, f1, f2, de)."""

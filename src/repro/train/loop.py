@@ -15,6 +15,11 @@ Responsibilities (DESIGN.md §6):
     it stops the loop cleanly at a step boundary (state is consistent, no
     final checkpoint is written) — the hook ``repro.elastic.manager`` uses
     to detect dead shards and hand control to the rescale path.
+  * profiler spans: each iteration is a ``repro.train.step`` step marker
+    (``jax.profiler.StepTraceAnnotation``) holding the non-overlapping
+    spans ``repro.train.next_batch``, ``.dispatch``, ``.sync``, ``.fence``
+    and ``.checkpoint``, on the device trace's clock when a profiler trace
+    is taken; without one each costs about a microsecond.
 """
 
 from __future__ import annotations
@@ -25,6 +30,7 @@ from typing import Any, Callable, Dict, Iterable, Optional
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.train.checkpoint import CheckpointManager
 
@@ -105,36 +111,42 @@ def run_training(
 
     try:
         for step in range(start_step, loop_cfg.total_steps):
-            batch = to_device(data_iter.next_batch())
-            t0 = time.perf_counter()
-            state, metrics = jitted(state, batch)
-            loss = float(metrics["loss"])   # blocks: device sync = honest timing
-            dt = time.perf_counter() - t0
-            step_times.append(dt)
-            losses.append(loss)
+            with StepTraceAnnotation("repro.train.step", step_num=step):
+                with TraceAnnotation("repro.train.next_batch"):
+                    batch = to_device(data_iter.next_batch())
+                t0 = time.perf_counter()
+                with TraceAnnotation("repro.train.dispatch"):
+                    state, metrics = jitted(state, batch)
+                with TraceAnnotation("repro.train.sync"):
+                    loss = float(metrics["loss"])   # blocks: device sync = honest timing
+                dt = time.perf_counter() - t0
+                step_times.append(dt)
+                losses.append(loss)
 
-            if ewma is None:
-                ewma = dt
-            else:
-                if dt > loop_cfg.straggler_factor * ewma:
-                    stragglers += 1
-                ewma = (1 - loop_cfg.ewma_alpha) * ewma + loop_cfg.ewma_alpha * dt
+                if ewma is None:
+                    ewma = dt
+                else:
+                    if dt > loop_cfg.straggler_factor * ewma:
+                        stragglers += 1
+                    ewma = (1 - loop_cfg.ewma_alpha) * ewma + loop_cfg.ewma_alpha * dt
 
-            if on_metrics and step % loop_cfg.log_every == 0:
-                on_metrics(step, {"loss": loss, "step_time": dt, "ewma": ewma})
+                if on_metrics and step % loop_cfg.log_every == 0:
+                    on_metrics(step, {"loss": loss, "step_time": dt, "ewma": ewma})
 
-            if fence is not None and (step + 1) % loop_cfg.fence_every == 0:
-                try:
-                    fence(step)
-                except FenceInterrupt:
-                    interrupted_at = step + 1
-                    break
+                if fence is not None and (step + 1) % loop_cfg.fence_every == 0:
+                    try:
+                        with TraceAnnotation("repro.train.fence"):
+                            fence(step)
+                    except FenceInterrupt:
+                        interrupted_at = step + 1
+                        break
 
-            if ckpt is not None and (step + 1) % loop_cfg.ckpt_every == 0:
-                extra = dict(extra_base or {})
-                if hasattr(data_iter, "state_dict"):
-                    extra["data"] = data_iter.state_dict()
-                ckpt.save(step + 1, state, extra, topology=topology)
+                if ckpt is not None and (step + 1) % loop_cfg.ckpt_every == 0:
+                    with TraceAnnotation("repro.train.checkpoint"):
+                        extra = dict(extra_base or {})
+                        if hasattr(data_iter, "state_dict"):
+                            extra["data"] = data_iter.state_dict()
+                        ckpt.save(step + 1, state, extra, topology=topology)
 
         if ckpt is not None and interrupted_at is None:
             extra = dict(extra_base or {})

@@ -185,17 +185,20 @@ def make_gnn_train_step(cfg: GNNConfig,
         if cached:
             def loss_fn(p, c):
                 h, new_c = model.apply_cached(p, view, c)
-                return gnn.node_loss(_logits(p, h), batch["labels"]), new_c
+                with jax.named_scope("head_loss"):
+                    return gnn.node_loss(_logits(p, h), batch["labels"]), new_c
             (loss, new_cache), g = jax.value_and_grad(
                 loss_fn, has_aux=True, allow_int=True)(
                     state["params"], state["cache"])
         else:
             def loss_fn(p):
                 h = model.apply(p, view)
-                return gnn.node_loss(_logits(p, h), batch["labels"])
+                with jax.named_scope("head_loss"):
+                    return gnn.node_loss(_logits(p, h), batch["labels"])
             loss, g = jax.value_and_grad(loss_fn, allow_int=True)(state["params"])
 
-        params, opt_state = adamw_update(state["params"], g, state["opt"], ocfg)
+        with jax.named_scope("adamw"):
+            params, opt_state = adamw_update(state["params"], g, state["opt"], ocfg)
         new_state = {"params": params, "opt": opt_state, "step": state["step"] + 1}
         metrics = {"loss": loss}
         if cached:

@@ -14,6 +14,7 @@ compiles live in this one file so one worker holds the library.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -75,11 +76,11 @@ def test_hash_decode_compiles_for_v5e(one_chip, dtype):
     assert "hash_decode" in text
 
 
-def test_sage_train_step_compiles_for_v5e(one_chip):
+@pytest.fixture(scope="module")
+def sage_step(one_chip):
     """The one-chip SAGE train step at the paper widths (batch 1024, fanout
-    15, the 53,248-row frontier ``chip_smoke.py`` runs), from shapes of the
-    state and of one frontier batch: it compiles with the kernel in it and
-    fits the chip's memory."""
+    15, the 53,248-row frontier ``chip_smoke.py`` runs), compiled for the
+    described chip from shapes of the state and of one frontier batch."""
     from repro.configs.paper_gnn import paper_gnn_config
     from repro.core.codes import n_words
     from repro.graph import NeighborSampler, powerlaw_graph
@@ -108,10 +109,31 @@ def test_sage_train_step_compiles_for_v5e(one_chip):
                                   src.next_batch())
 
     step = make_gnn_train_step(cfg, interpret=False)
-    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+    return jax.jit(step, donate_argnums=(0,)).lower(
         state, frontier_batch).compile()
-    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
-    mem = compiled.memory_analysis()
+
+
+def test_sage_train_step_compiles_for_v5e(sage_step):
+    """The step compiles with the kernel in it and fits the chip's memory."""
+    assert 'custom_call_target="tpu_custom_call"' in sage_step.as_text()
+    mem = sage_step.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < total < V5E_HBM_BYTES, mem
+
+
+def test_sage_train_step_ops_carry_layer_scopes(sage_step):
+    """The step's named scopes reach the compiled ops' ``op_name`` metadata
+    (the backward's as ``transpose(jvp(decode))``), and the kernel's custom
+    call keeps the instruction name the benchmark's trace reduction finds."""
+    from bench import trace as tr
+    text = sage_step.as_text()
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    scopes = {part for name in op_names for part in re.split(r"[/()]", name)}
+    for scope in ("decode", "decoder_mlp", "sage", "head_loss", "adamw"):
+        assert scope in scopes, scope
+    assert any("transpose(jvp(decode))" in name for name in op_names)
+    kernels = [m.group(1) for m in re.finditer(
+        r"^\s*(?:ROOT )?%([\w.-]+) = .*custom-call\(.*"
+        r'custom_call_target="tpu_custom_call"', text, re.M)]
+    assert kernels and all(tr.KERNEL.search(k) for k in kernels), kernels
