@@ -139,6 +139,125 @@ def test_hash_decode_int8_kernel_matches_ref(B, m, c, d_c):
                                    rtol=2e-4, atol=2e-4)
 
 
+def _hard_f32(key, shape):
+    """Random normal f32 values at exponents 2^-100..2^100, of both signs,
+    with the split's hard cases (2 - 2^-8 + 2^-23, ±0) laid over them."""
+    k1, k2 = jax.random.split(key)
+    x = np.asarray(jax.random.normal(k1, shape, jnp.float32))
+    exps = np.asarray(jax.random.randint(k2, shape, -100, 101))
+    x = (x * np.exp2(exps.astype(np.float64))).astype(np.float32).ravel()
+    hard = np.array([2 - 2**-8 + 2**-23, -(2 - 2**-8 + 2**-23), 0.0, -0.0,
+                     1.0, -1.0, 2.0**-100, -(2.0**100), 3 * 2.0**-23],
+                    np.float32)
+    x[::97][:hard.size] = hard
+    return jnp.asarray(x.reshape(shape))
+
+
+def _bits(x):
+    """f32 bit patterns with -0 folded into +0: an accumulator that starts
+    at +0 (the kernel's) turns a -0 sum into +0, the gather's keeps it."""
+    return (np.asarray(x, np.float32) + np.float32(0)).view(np.uint32)
+
+
+def test_split_bf16_exact_in_every_order():
+    """hi + mid + lo == x bit for bit in all six orders of summation (-0
+    sums to +0), so the MXU may add the parts in any order."""
+    import itertools
+    from repro.kernels.hash_decode.kernel import split_bf16
+    x = np.concatenate([
+        np.asarray(jax.random.normal(jax.random.PRNGKey(21), (100_000,))),
+        np.asarray(_hard_f32(jax.random.PRNGKey(22), (4096,))).ravel(),
+        np.exp2(np.arange(-100, 101)).astype(np.float32),
+        -np.exp2(np.arange(-100, 101)).astype(np.float32),
+    ]).astype(np.float32)
+    parts = jax.jit(split_bf16)(jnp.asarray(x))
+    assert parts.dtype == jnp.bfloat16 and parts.shape == (3,) + x.shape
+    parts = np.asarray(parts.astype(jnp.float32))
+    for a, b, c in itertools.permutations(range(3)):
+        np.testing.assert_array_equal(
+            _bits((parts[a] + parts[b]) + parts[c]), _bits(x))
+    # -0 splits into (-0, +0, +0): its sum is +0 in every order
+    neg0 = parts[:, np.signbit(x) & (x == 0)]
+    assert neg0.size and (neg0.sum(axis=0) == 0).all()
+
+
+@pytest.mark.parametrize("block_d", [128, 256])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_hash_decode_bitwise_equals_gather(dtype, block_d):
+    """At the paper widths the kernel's output equals the gather oracle's
+    bit for bit, for f32 codebooks (three exact bf16 parts) and bf16 ones
+    (one part), with and without w0: every product is exact and the m
+    codebooks accumulate in the gather's order."""
+    from repro.core.backend import GatherBackend
+    from repro.kernels.hash_decode.kernel import hash_decode_fwd
+    B, m, c, d_c = 256, 16, 256, 512
+    key = jax.random.PRNGKey(23)
+    codes = jax.random.randint(key, (B, m), 0, c)
+    cb = _hard_f32(jax.random.fold_in(key, 1), (m, c, d_c)).astype(dtype)
+    w0 = jax.random.normal(jax.random.fold_in(key, 2), (d_c,), dtype)
+    gather = GatherBackend()
+    for w in (None, w0):
+        out = hash_decode_fwd(codes, cb, w, block_d=block_d, interpret=True)
+        ref = gather.decode(codes, cb, w)
+        assert out.dtype == jnp.float32
+        np.testing.assert_array_equal(_bits(out), _bits(ref))
+
+
+def test_hash_decode_int8_unchanged_bitwise():
+    """The int8 path keeps its scaled one-hot at HIGHEST: bitwise equal to
+    hash_decode_ref's dequantized codebooks summed in codebook order (the
+    int8 gather oracle; the ref's one einsum sums in another order)."""
+    from repro.core.backend import GatherBackend, MixedPrecisionPolicy
+    from repro.kernels.hash_decode.kernel import hash_decode_fwd
+    B, m, c, d_c = 256, 16, 256, 512
+    key = jax.random.PRNGKey(24)
+    codes = jax.random.randint(key, (B, m), 0, c)
+    cb = jax.random.normal(jax.random.fold_in(key, 1), (m, c, d_c))
+    w0 = jax.random.normal(jax.random.fold_in(key, 2), (d_c,))
+    q, scales = hd_ops.quantize_codebooks(cb)
+    gather = GatherBackend(MixedPrecisionPolicy(quantize="int8"))
+    for w in (None, w0):
+        out = hash_decode_fwd(codes, q, w, scales, interpret=True)
+        np.testing.assert_array_equal(_bits(out),
+                                      _bits(gather.decode(codes, cb, w)))
+
+
+@pytest.mark.parametrize("dtype,passes", [(jnp.float32, 3),
+                                          (jnp.bfloat16, 1)])
+def test_hash_decode_single_pass_bf16_structure(dtype, passes):
+    """The kernel body holds only single-pass bf16 x bf16 dots with f32
+    accumulation, ``passes`` per codebook, and the batch is the grid's
+    innermost axis (each codebook panel is fetched once per call)."""
+    from repro.kernels.hash_decode.kernel import hash_decode_fwd
+    B, m, c, d_c, block_b, block_d = 768, 16, 256, 512, 256, 256
+    # traced as the benchmark runs it: a dot that states no precision
+    # would take this global default
+    with jax.default_matmul_precision("highest"):
+        jaxpr = jax.make_jaxpr(lambda codes, cb, w0: hash_decode_fwd(
+            codes, cb, w0, block_b=block_b, block_d=block_d,
+            interpret=True))(
+            jnp.zeros((B, m), jnp.int32), jnp.zeros((m, c, d_c), dtype),
+            jnp.zeros((d_c,), dtype))
+
+    def eqns(jp):
+        for e in jp.eqns:
+            yield e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from eqns(sub)
+
+    calls = [e for e in eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    call = calls[0]
+    assert call.params["grid_mapping"].grid == (d_c // block_d, B // block_b)
+    dots = [e for e in eqns(call.params["jaxpr"])
+            if e.primitive.name == "dot_general"]
+    assert len(dots) == passes * m
+    for e in dots:
+        assert [v.aval.dtype for v in e.invars] == [jnp.bfloat16] * 2
+        assert e.params["preferred_element_type"] == jnp.float32
+        assert e.params["precision"] == (jax.lax.Precision.DEFAULT,) * 2
+
+
 def test_quantize_codebooks_roundtrip_bound():
     """Absmax int8: dequant error per element <= scale/2, scale = absmax/127,
     and all-zero code vectors reconstruct exactly (scale forced to 1)."""
