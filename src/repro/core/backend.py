@@ -495,6 +495,10 @@ class ShardedBackend(DecodeBackend):
 # owner-computes (cross-shard dedup) decode
 # ---------------------------------------------------------------------------
 
+# the scope every collective of the owner exchange runs under (op_name)
+EXCHANGE_SCOPE = "owner_exchange"
+
+
 def _owner_decode(base: DecodeBackend, mesh, axis: str,
                   codes: Array, codebooks, w0: Array, plan) -> Array:
     """Owner-computes cross-shard frontier decode under ``shard_map``.
@@ -520,7 +524,12 @@ def _owner_decode(base: DecodeBackend, mesh, axis: str,
     cotangent is accumulated exactly once, on its owner, before one
     ``base.decode`` VJP per owner produces disjoint codebook partials (the
     closing ``psum`` only sums those disjoint partials into the replicated
-    codebook gradient; no duplicate row is ever double-counted)."""
+    codebook gradient; no duplicate row is ever double-counted).
+
+    Every collective of both exchanges runs under the ``owner_exchange``
+    scope (``EXCHANGE_SCOPE``), so in a train step its ``op_name`` reads
+    ``jvp(decode)/shard_map/owner_exchange/...`` forward and
+    ``transpose(jvp(decode))/shard_map/owner_exchange/...`` backward."""
     from jax.sharding import PartitionSpec as P
 
     from repro.parallel.sharding import all_to_all
@@ -536,7 +545,8 @@ def _owner_decode(base: DecodeBackend, mesh, axis: str,
     def _owned_codes(codes_l, rr, os_l):
         """Requester-side gather + all_to_all + owner-side dedup gather."""
         send = codes_l[jnp.clip(rr, 0, cap - 1)]            # (n, oc, m)
-        recv = all_to_all(send, axis)                       # (n, oc, m)
+        with jax.named_scope(EXCHANGE_SCOPE):
+            recv = all_to_all(send, axis)                   # (n, oc, m)
         return recv.reshape(n * oc, -1)[os_l]               # (ou, m)
 
     @jax.custom_vjp
@@ -544,10 +554,12 @@ def _owner_decode(base: DecodeBackend, mesh, axis: str,
         def local(codes_l, rr_l, os_l, ri_l, cb_, w0_):
             rr = rr_l[0]
             dec = base.decode(_owned_codes(codes_l, rr, os_l[0]), cb_, w0_)
-            back = all_to_all(dec[ri_l[0]], axis)           # (n, oc, d)
+            with jax.named_scope(EXCHANGE_SCOPE):
+                back = all_to_all(dec[ri_l[0]], axis)       # (n, oc, d)
             out_l = jnp.zeros((cap, d), dec.dtype).at[rr.reshape(-1)].set(
                 back.reshape(-1, d), mode="drop")           # sentinel cap drops
-            return jax.lax.all_gather(out_l, axis, axis=0, tiled=True)
+            with jax.named_scope(EXCHANGE_SCOPE):
+                return jax.lax.all_gather(out_l, axis, axis=0, tiled=True)
         return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(axis, None),) + plan_specs + (cb_specs, P(None)),
@@ -568,7 +580,8 @@ def _owner_decode(base: DecodeBackend, mesh, axis: str,
             g_blk = jax.lax.dynamic_slice_in_dim(g_full, s * cap, cap, 0)
             g_send = (g_blk[jnp.clip(rr, 0, cap - 1)]
                       * (rr < cap)[..., None].astype(g_full.dtype))
-            g_recv = all_to_all(g_send, axis)               # (n, oc, d)
+            with jax.named_scope(EXCHANGE_SCOPE):
+                g_recv = all_to_all(g_send, axis)           # (n, oc, d)
             # reduce_dtype contract: the per-requester scatter-add onto the
             # owned rows accumulates in f32
             ghat = jnp.zeros((ou, d), jnp.float32).at[
@@ -576,8 +589,10 @@ def _owner_decode(base: DecodeBackend, mesh, axis: str,
                     g_recv.reshape(-1, d).astype(jnp.float32))
             _, vjp = jax.vjp(lambda c, sc: base.decode(owned, c, sc), cb_, w0_)
             gcb, gw0 = vjp(ghat.astype(g_full.dtype))
-            gcb = _psum_f32(gcb, cb_, axis)
-            gw0 = jax.lax.psum(gw0.astype(jnp.float32), axis).astype(w0_.dtype)
+            with jax.named_scope(EXCHANGE_SCOPE):
+                gcb = _psum_f32(gcb, cb_, axis)
+                gw0 = jax.lax.psum(gw0.astype(jnp.float32),
+                                   axis).astype(w0_.dtype)
             return gcb, gw0
 
         gcb, gw0 = jax.shard_map(

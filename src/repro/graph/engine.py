@@ -40,6 +40,7 @@ from __future__ import annotations
 import dataclasses
 import queue
 import threading
+import time
 from typing import Any, Dict, Optional, Sequence, Union
 
 import jax
@@ -286,6 +287,13 @@ class ShardedSageBatchSource:
     capacities is emitted WITHOUT a plan after a loud warning (the owner
     backend then falls back to the sharded row-partition decode) — rows are
     never silently truncated.
+
+    Each plan build is a ``repro.producer.owner_plan`` profiler span (inside
+    the producer's ``repro.producer.sample``), and ``stats()`` counts what
+    planning cost and yielded: ``owner_plan_us`` (the same clock reads as
+    the span), ``owned_rows`` (the sum of every plan's ``n_owned``: the rows
+    the owners decode) and ``owner_plan_overflows`` (batches emitted without
+    a plan).  ``PrefetchIterator.stats()`` passes them on.
     """
 
     def __init__(self, sampler: NeighborSampler, nodes, labels,
@@ -323,6 +331,9 @@ class ShardedSageBatchSource:
         self.owner_cap = oc if owner_cap is None else int(owner_cap)
         self.owner_unique_cap = (ou if owner_unique_cap is None
                                  else int(owner_unique_cap))
+        self.owner_plan_us = 0.0
+        self.owned_rows = 0
+        self.owner_plan_overflows = 0
 
     def measure_duplication(self) -> float:
         """Measured decode duplication of the upcoming batch:
@@ -364,11 +375,15 @@ class ShardedSageBatchSource:
         labels = np.concatenate([p["labels"] for p in parts])
         plan = None
         if self.owner_plan:
-            plan = build_owner_plan(
-                [np.asarray(fb.unique) for fb in fbs],
-                [int(fb.n_unique) for fb in fbs],
-                self.n_shards, self.owner_cap, self.owner_unique_cap)
+            with TraceAnnotation("repro.producer.owner_plan"):
+                t0 = time.perf_counter()
+                plan = build_owner_plan(
+                    [np.asarray(fb.unique) for fb in fbs],
+                    [int(fb.n_unique) for fb in fbs],
+                    self.n_shards, self.owner_cap, self.owner_unique_cap)
+                self.owner_plan_us += (time.perf_counter() - t0) * 1e6
             if plan is None:
+                self.owner_plan_overflows += 1
                 import warnings
                 warnings.warn(
                     f"owner plan overflow: a (requester, owner) bucket "
@@ -379,8 +394,19 @@ class ShardedSageBatchSource:
                     f"— correct, but no cross-shard dedup).  Raise the caps "
                     f"(RuntimeSpec.owner_cap / owner_unique_cap) if this "
                     f"recurs.", stacklevel=2)
+            else:
+                self.owned_rows += int(plan.n_owned.sum())
         return {"frontier": FrontierBatch(unique, maps, n_unique, valid, plan),
                 "labels": labels}
+
+    def stats(self) -> Dict[str, float]:
+        """Owner planning over every batch built so far (nothing without
+        ``owner_plan``); see the class docstring."""
+        if not self.owner_plan:
+            return {}
+        return {"owner_plan_us": self.owner_plan_us,
+                "owned_rows": self.owned_rows,
+                "owner_plan_overflows": self.owner_plan_overflows}
 
     # -- checkpointable state -------------------------------------------
     def state_dict(self) -> Dict[str, int]:
@@ -656,8 +682,11 @@ class PrefetchIterator:
         """Cumulative producer-side accounting: per-stage wall-clock
         (``sample_us`` sampling + source bookkeeping, ``code_gather_us``
         host code-row gather, ``put_us`` device put incl. the blocking H2D
-        copy), produced-batch count, and code-row transfer volume."""
+        copy), produced-batch count, and code-row transfer volume; plus the
+        source's own counters where it keeps some (the owner plan's,
+        ``ShardedSageBatchSource.stats``)."""
         n = self._n_produced
+        source_stats = getattr(self.source, "stats", None)
         return {
             "n_produced": n,
             "sample_us": self._sample_us,
@@ -666,6 +695,7 @@ class PrefetchIterator:
             "transferred_code_bytes": self._transferred_code_bytes,
             "transferred_code_bytes_per_batch": (
                 self._transferred_code_bytes / n if n else 0.0),
+            **(source_stats() if callable(source_stats) else {}),
         }
 
     # -- checkpointable state -------------------------------------------

@@ -137,3 +137,79 @@ def test_sage_train_step_ops_carry_layer_scopes(sage_step):
         r"^\s*(?:ROOT )?%([\w.-]+) = .*custom-call\(.*"
         r'custom_call_target="tpu_custom_call"', text, re.M)]
     assert kernels and all(tr.KERNEL.search(k) for k in kernels), kernels
+
+
+@pytest.fixture(scope="module")
+def owner_step(topo):
+    """The four-chip owner-decode SAGE train step of the benchmark's
+    ``sage-products.train-owner4`` cell (1,024 targets a shard, fanout 15,
+    ``frontier_cap`` 122,880, owner caps 38,400 / 73,728) compiled for the
+    described ``v5e:2x2``: the state replicated, the frontier and its owner
+    plan placed as the runtime places them."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from repro.configs.paper_gnn import paper_gnn_config
+    from repro.core.codes import n_words
+    from repro.graph import NeighborSampler, powerlaw_graph
+    from repro.graph.engine import ShardedSageBatchSource
+    from repro.parallel.policy import frontier_batch_shardings
+    from repro.train import init_gnn_train_state, make_gnn_train_step
+
+    n_nodes, per_shard, cap = 500_000, 1024, 122_880
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(4), ("data",))
+    cfg = paper_gnn_config("sage", n_nodes=n_nodes, n_classes=47, fanout=15)
+    cfg = dataclasses.replace(cfg, embedding=dataclasses.replace(
+        cfg.embedding, lookup_impl="owner:pallas"))
+    ecfg = cfg.embedding_config()
+    codes = jax.ShapeDtypeStruct((n_nodes, n_words(ecfg.c, ecfg.m)),
+                                 jnp.uint32)
+    state = jax.eval_shape(
+        lambda c: init_gnn_train_state(jax.random.PRNGKey(0), cfg, codes=c),
+        codes)
+    state = jax.tree.map(
+        lambda x: _sds(x, NamedSharding(mesh, PartitionSpec())), state)
+
+    # the batch's shapes (caps, plan) do not depend on the graph's size
+    adj, labels = powerlaw_graph(0, 3000, avg_degree=10, n_classes=47)
+    sampler = NeighborSampler(adj, cfg.fanouts, max_deg=64, seed=0)
+    src = ShardedSageBatchSource(sampler, np.arange(3000), labels, per_shard,
+                                 n_shards=4, frontier_cap=cap, owner_plan=True,
+                                 owner_cap=38_400, owner_unique_cap=73_728)
+    batch = src.next_batch()
+    assert batch["frontier"].plan is not None
+    batch = jax.tree.map(lambda x, s: _sds(np.asarray(x), s), batch,
+                         frontier_batch_shardings(batch, mesh))
+
+    step = make_gnn_train_step(cfg, interpret=False, mesh=mesh)
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(step, donate_argnums=(0,)).lower(state, batch).compile()
+
+
+def test_owner_train_step_compiles_for_v5e_2x2(owner_step):
+    """The owner step compiles with the kernel in it, once (the backward's
+    repeated decode of the owned rows leaves no second kernel call), and
+    fits each chip's memory."""
+    text = owner_step.as_text()
+    kernels = re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = .*custom-call\(.*"
+                         r'custom_call_target="tpu_custom_call"', text, re.M)
+    assert kernels == ["hash_decode.1"], kernels
+    mem = owner_step.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < total < V5E_HBM_BYTES, mem
+
+
+def test_owner_exchange_collectives_carry_their_scope(owner_step):
+    """Every all-to-all of the owner exchange, forward and backward, and the
+    codebook gradient's psum carry ``owner_exchange`` under the step's
+    ``decode`` scope in their ``op_name``."""
+    from repro.core.backend import EXCHANGE_SCOPE
+    ops = re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = .*?"
+                     r'metadata=\{op_name="([^"]*)"', owner_step.as_text(), re.M)
+    a2a = [(n, o) for n, o in ops if re.match(r"all[-_]to[-_]all", n)]
+    assert {o.split("/shard_map/")[0] for _, o in a2a} == {
+        "jit(train_step)/jvp(decode)", "jit(train_step)/transpose(jvp(decode))"}
+    assert all(f"/{EXCHANGE_SCOPE}/" in o for _, o in a2a), a2a
+    psums = [o for n, o in ops if n.startswith("all-reduce") and "decode" in o]
+    assert psums and all(
+        o.startswith("jit(train_step)/transpose(jvp(decode))")
+        and f"/{EXCHANGE_SCOPE}/psum" in o for o in psums), psums
