@@ -38,10 +38,12 @@ rather than statistical.
 from __future__ import annotations
 
 import dataclasses
+import os
 import queue
 import threading
 import time
-from typing import Any, Dict, Optional, Sequence, Union
+from concurrent.futures import ThreadPoolExecutor
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import numpy as np
@@ -256,6 +258,30 @@ class SageBatchSource:
         self.step = int(state["step"])
 
 
+# The sharded producer's thread pool: one for the process, made on first use
+# and shared by every ``ShardedSageBatchSource`` (tests and the elastic
+# manager build sources by the dozen, so none may start threads of its own).
+# Its work is numpy that releases the GIL: hashing, gathers, sorts.
+SHARD_POOL_WORKERS = min(os.cpu_count() or 1, 8)
+_pool: Optional[ThreadPoolExecutor] = None
+_pool_lock = threading.Lock()
+
+
+def _shard_pool() -> ThreadPoolExecutor:
+    global _pool
+    with _pool_lock:
+        if _pool is None:
+            _pool = ThreadPoolExecutor(SHARD_POOL_WORKERS,
+                                       thread_name_prefix="shard-pool")
+        return _pool
+
+
+def _timed_next_batch(src: SageBatchSource) -> Tuple[Dict[str, Any], float]:
+    t0 = time.perf_counter()
+    part = src.next_batch()
+    return part, (time.perf_counter() - t0) * 1e6
+
+
 class ShardedSageBatchSource:
     """All-shard view of the sharded stream: N per-shard ``SageBatchSource``s
     advanced in lockstep, their batches stacked into one *global* batch.
@@ -288,12 +314,22 @@ class ShardedSageBatchSource:
     backend then falls back to the sharded row-partition decode) — rows are
     never silently truncated.
 
-    Each plan build is a ``repro.producer.owner_plan`` profiler span (inside
-    the producer's ``repro.producer.sample``), and ``stats()`` counts what
-    planning cost and yielded: ``owner_plan_us`` (the same clock reads as
-    the span), ``owned_rows`` (the sum of every plan's ``n_owned``: the rows
-    the owners decode) and ``owner_plan_overflows`` (batches emitted without
-    a plan).  ``PrefetchIterator.stats()`` passes them on.
+    The shards sample on the process's shard pool at once, and the plan
+    is built there in two phases (per requester, then per owner; see
+    ``build_owner_plan``); the stacking stays on the calling thread.  Every
+    part is a pure function of ``(seed, shard, step)`` or of the four
+    frontiers, so the batch is bit for bit the one a serial build gives.
+
+    Each plan build is a ``repro.producer.owner_plan`` profiler span on the
+    calling thread (inside the producer's ``repro.producer.sample``) around
+    both pooled phases, and ``stats()`` counts what sampling and planning
+    cost and yielded: ``shard_workers`` (the pool threads this source's
+    shards run on), ``shard_sample_us`` (the sum over shards of each shard's
+    own sample-and-dedup time, so over the sampling's wall time it reads
+    how far the shards overlapped), ``owner_plan_us`` (the same clock reads
+    as the span), ``owned_rows`` (the sum of every plan's ``n_owned``: the
+    rows the owners decode) and ``owner_plan_overflows`` (batches emitted
+    without a plan).  ``PrefetchIterator.stats()`` passes them on.
     """
 
     def __init__(self, sampler: NeighborSampler, nodes, labels,
@@ -331,9 +367,17 @@ class ShardedSageBatchSource:
         self.owner_cap = oc if owner_cap is None else int(owner_cap)
         self.owner_unique_cap = (ou if owner_unique_cap is None
                                  else int(owner_unique_cap))
+        self.shard_workers = min(SHARD_POOL_WORKERS, self.n_shards)
+        self.shard_sample_us = 0.0
         self.owner_plan_us = 0.0
         self.owned_rows = 0
         self.owner_plan_overflows = 0
+
+    def _sample_shards(self) -> Tuple[List[Dict[str, Any]], List[float]]:
+        """Every shard's next batch, sampled on the pool, with each
+        shard's own sample-and-dedup time in us."""
+        timed = list(_shard_pool().map(_timed_next_batch, self.shards))
+        return [p for p, _ in timed], [us for _, us in timed]
 
     def measure_duplication(self) -> float:
         """Measured decode duplication of the upcoming batch:
@@ -345,7 +389,7 @@ class ShardedSageBatchSource:
         next ``next_batch`` at the same step reuses instead of resampling),
         so resume stays exact and the step-0 sampling cost is paid once."""
         step0 = self.shards[0].step
-        parts = [s.next_batch() for s in self.shards]
+        parts, _ = self._sample_shards()
         for s in self.shards:
             s.step = step0
         self._peek = (step0, parts)
@@ -359,7 +403,8 @@ class ShardedSageBatchSource:
             for s in self.shards:       # advance as next_batch would have
                 s.step += 1
         else:
-            parts = [s.next_batch() for s in self.shards]
+            parts, shard_us = self._sample_shards()
+            self.shard_sample_us += sum(shard_us)
         self._peek = None
         cap = self.frontier_cap
         fbs = [p["frontier"] for p in parts]
@@ -380,7 +425,8 @@ class ShardedSageBatchSource:
                 plan = build_owner_plan(
                     [np.asarray(fb.unique) for fb in fbs],
                     [int(fb.n_unique) for fb in fbs],
-                    self.n_shards, self.owner_cap, self.owner_unique_cap)
+                    self.n_shards, self.owner_cap, self.owner_unique_cap,
+                    map_fn=_shard_pool().map)
                 self.owner_plan_us += (time.perf_counter() - t0) * 1e6
             if plan is None:
                 self.owner_plan_overflows += 1
@@ -400,13 +446,15 @@ class ShardedSageBatchSource:
                 "labels": labels}
 
     def stats(self) -> Dict[str, float]:
-        """Owner planning over every batch built so far (nothing without
-        ``owner_plan``); see the class docstring."""
-        if not self.owner_plan:
-            return {}
-        return {"owner_plan_us": self.owner_plan_us,
-                "owned_rows": self.owned_rows,
-                "owner_plan_overflows": self.owner_plan_overflows}
+        """Sampling, and owner planning where the source plans, over every
+        batch built so far; see the class docstring."""
+        out = {"shard_workers": self.shard_workers,
+               "shard_sample_us": self.shard_sample_us}
+        if self.owner_plan:
+            out.update(owner_plan_us=self.owner_plan_us,
+                       owned_rows=self.owned_rows,
+                       owner_plan_overflows=self.owner_plan_overflows)
+        return out
 
     # -- checkpointable state -------------------------------------------
     def state_dict(self) -> Dict[str, int]:

@@ -38,7 +38,7 @@ the N-shard union bit-identical to the 1-shard batch.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -177,9 +177,51 @@ def remap_shard_state(state: dict, n_shards: int, shard: int = 0) -> dict:
     }
 
 
+def bucket_requests(ids: np.ndarray, n_shards: int, owner_cap: int,
+                    req_rows: np.ndarray) -> Optional[List[np.ndarray]]:
+    """One requester's half of ``build_owner_plan``: bucket its valid
+    frontier ``ids`` by owner ``id % n_shards``, write each bucket's row
+    indices into ``req_rows[o]`` (the requester's (n, owner_cap) slice of
+    the plan) and return the n per-owner id lists, or ``None`` when a bucket
+    exceeds ``owner_cap``."""
+    own = ids % n_shards
+    requests = []
+    for o in range(n_shards):
+        rows = np.nonzero(own == o)[0]
+        if rows.shape[0] > owner_cap:
+            return None                         # bucket overflow: loud fallback
+        req_rows[o, :rows.shape[0]] = rows
+        requests.append(ids[rows])
+    return requests
+
+
+def dedup_owner(requests: Sequence[np.ndarray], owner_cap: int,
+                owner_unique_cap: int, owned_src: np.ndarray,
+                ret_idx: np.ndarray) -> Optional[int]:
+    """One owner's half of ``build_owner_plan``: dedup the id lists its n
+    requesters sent (``requests[s]``), write ``owned_src`` / ``ret_idx``
+    (the owner's slices of the plan) and return its owned-unique count, or
+    ``None`` when that exceeds ``owner_unique_cap``."""
+    n = len(requests)
+    # the received buffer: requester s's segment at offset s*owner_cap
+    flat = np.full((n * owner_cap,), -1, np.int64)
+    for s, req in enumerate(requests):
+        flat[s * owner_cap:s * owner_cap + req.shape[0]] = req
+    pos = np.nonzero(flat >= 0)[0]
+    uniq, first, inv = np.unique(flat[pos], return_index=True,
+                                 return_inverse=True)
+    if uniq.shape[0] > owner_unique_cap:
+        return None                             # owned overflow: loud fallback
+    owned_src[:uniq.shape[0]] = pos[first]
+    ridx = np.zeros((n * owner_cap,), np.int32)
+    ridx[pos] = inv.astype(np.int32)
+    ret_idx[...] = ridx.reshape(n, owner_cap)
+    return int(uniq.shape[0])
+
+
 def build_owner_plan(uniques: Sequence[np.ndarray], n_uniques: Sequence[int],
-                     n_shards: int, owner_cap: int,
-                     owner_unique_cap: int) -> Optional[OwnerPlan]:
+                     n_shards: int, owner_cap: int, owner_unique_cap: int, *,
+                     map_fn: Callable) -> Optional[OwnerPlan]:
     """Build the owner-computes exchange plan for one stacked frontier.
 
     ``uniques``: the n_shards per-shard frontier blocks (each (cap,) int32,
@@ -189,40 +231,32 @@ def build_owner_plan(uniques: Sequence[np.ndarray], n_uniques: Sequence[int],
     ``None`` when any (requester, owner) bucket exceeds ``owner_cap`` or any
     owner's unique set exceeds ``owner_unique_cap`` — the caller must fall
     back loudly (emit the batch without a plan), NEVER truncate: a dropped
-    row would silently decode to zeros."""
+    row would silently decode to zeros.
+
+    Two phases, each a ``map_fn`` over independent parts that write disjoint
+    slices of the plan: ``bucket_requests`` per requester, then
+    ``dedup_owner`` per owner.  ``map_fn`` is the caller's map (the sharded
+    source passes its thread pool's); the plan is the same whichever runs
+    the parts."""
     n = int(n_shards)
     cap = int(np.asarray(uniques[0]).shape[0])
     req_rows = np.full((n, n, owner_cap), cap, np.int32)
-    requests = [[None] * n for _ in range(n)]
-    for s in range(n):
-        ids = np.asarray(uniques[s])[:int(n_uniques[s])]
-        own = ids % n
-        for o in range(n):
-            rows = np.nonzero(own == o)[0]
-            if rows.shape[0] > owner_cap:
-                return None                     # bucket overflow: loud fallback
-            req_rows[s, o, :rows.shape[0]] = rows
-            requests[s][o] = ids[rows]
+    requests = list(map_fn(
+        lambda s: bucket_requests(np.asarray(uniques[s])[:int(n_uniques[s])],
+                                  n, owner_cap, req_rows[s]),
+        range(n)))
+    if any(r is None for r in requests):
+        return None
     owned_src = np.zeros((n, owner_unique_cap), np.int32)
     ret_idx = np.zeros((n, n, owner_cap), np.int32)
-    n_owned = np.zeros((n,), np.int32)
-    for o in range(n):
-        # owner o's received buffer: requester s's segment at offset s*owner_cap
-        flat = np.full((n * owner_cap,), -1, np.int64)
-        for s in range(n):
-            k = requests[s][o].shape[0]
-            flat[s * owner_cap:s * owner_cap + k] = requests[s][o]
-        pos = np.nonzero(flat >= 0)[0]
-        uniq, first, inv = np.unique(flat[pos], return_index=True,
-                                     return_inverse=True)
-        if uniq.shape[0] > owner_unique_cap:
-            return None                         # owned overflow: loud fallback
-        owned_src[o, :uniq.shape[0]] = pos[first]
-        n_owned[o] = uniq.shape[0]
-        ridx = np.zeros((n * owner_cap,), np.int32)
-        ridx[pos] = inv.astype(np.int32)
-        ret_idx[o] = ridx.reshape(n, owner_cap)
-    return OwnerPlan(req_rows, owned_src, ret_idx, n_owned)
+    n_owned = list(map_fn(
+        lambda o: dedup_owner([requests[s][o] for s in range(n)], owner_cap,
+                              owner_unique_cap, owned_src[o], ret_idx[o]),
+        range(n)))
+    if any(k is None for k in n_owned):
+        return None
+    return OwnerPlan(req_rows, owned_src, ret_idx,
+                     np.asarray(n_owned, np.int32))
 
 
 @jax.tree_util.register_pytree_node_class

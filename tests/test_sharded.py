@@ -19,6 +19,7 @@ from repro.configs.paper_gnn import paper_gnn_config
 from repro.core import backend as backend_mod
 from repro.core import embedding as emb_lib
 from repro.graph import FrontierBatch, NeighborSampler, powerlaw_graph
+from repro.graph.sampler import OwnerPlan
 from repro.graph.engine import (GNNModel, PrefetchIterator, SageBatchSource,
                                 ShardedSageBatchSource, default_frontier_cap)
 from repro.parallel.policy import make_frontier_placement
@@ -402,6 +403,158 @@ def test_owner_plan_overflow_falls_back_loudly(graph):
     g = single.next_batch()
     for lvl, got in zip(g["frontier"].levels(), fb.levels()):
         np.testing.assert_array_equal(np.asarray(lvl), np.asarray(got))
+
+
+def _serial_owner_plan(uniques, n_uniques, n, owner_cap, owner_unique_cap):
+    """Reference: the plan built loop by loop, one requester and then one
+    owner after another, with no pool."""
+    cap = uniques[0].shape[0]
+    req_rows = np.full((n, n, owner_cap), cap, np.int32)
+    requests = [[None] * n for _ in range(n)]
+    for s in range(n):
+        ids = uniques[s][:n_uniques[s]]
+        for o in range(n):
+            rows = np.nonzero(ids % n == o)[0]
+            if rows.shape[0] > owner_cap:
+                return None
+            req_rows[s, o, :rows.shape[0]] = rows
+            requests[s][o] = ids[rows]
+    owned_src = np.zeros((n, owner_unique_cap), np.int32)
+    ret_idx = np.zeros((n, n, owner_cap), np.int32)
+    n_owned = np.zeros((n,), np.int32)
+    for o in range(n):
+        flat = np.full((n * owner_cap,), -1, np.int64)
+        for s in range(n):
+            k = requests[s][o].shape[0]
+            flat[s * owner_cap:s * owner_cap + k] = requests[s][o]
+        pos = np.nonzero(flat >= 0)[0]
+        uniq, first, inv = np.unique(flat[pos], return_index=True,
+                                     return_inverse=True)
+        if uniq.shape[0] > owner_unique_cap:
+            return None
+        owned_src[o, :uniq.shape[0]] = pos[first]
+        n_owned[o] = uniq.shape[0]
+        ridx = np.zeros((n * owner_cap,), np.int32)
+        ridx[pos] = inv.astype(np.int32)
+        ret_idx[o] = ridx.reshape(n, owner_cap)
+    return OwnerPlan(req_rows, owned_src, ret_idx, n_owned)
+
+
+def _serial_batches(graph, n, seed, cap, plan_caps, steps, start=0):
+    """Reference: the stacked batches of ``steps`` steps from ``start``,
+    sampled shard after shard by plain ``SageBatchSource``s and planned by
+    ``_serial_owner_plan`` when ``plan_caps`` = (owner_cap,
+    owner_unique_cap) is given."""
+    adj, labels = graph
+    sampler = NeighborSampler(adj, (5, 5), max_deg=32, seed=0)
+    shards = [SageBatchSource(sampler, np.arange(N), labels, BATCH // n,
+                              seed=seed, pad_to=64, shard=s, n_shards=n,
+                              frontier_cap=cap) for s in range(n)]
+    for sh in shards:
+        sh.step = start
+    out = []
+    for _ in range(steps):
+        fbs = [sh.next_batch() for sh in shards]
+        labels_ = np.concatenate([p["labels"] for p in fbs])
+        fbs = [p["frontier"] for p in fbs]
+        unique = np.concatenate([fb.unique for fb in fbs])
+        maps = tuple(np.concatenate([fb.index_maps[i] + s * cap
+                                     for s, fb in enumerate(fbs)])
+                     for i in range(len(fbs[0].index_maps)))
+        valid = np.concatenate([np.arange(cap, dtype=np.int32) < int(fb.n_unique)
+                                for fb in fbs])
+        n_unique = np.int32(sum(int(fb.n_unique) for fb in fbs))
+        plan = None if plan_caps is None else _serial_owner_plan(
+            [fb.unique for fb in fbs], [int(fb.n_unique) for fb in fbs], n,
+            *plan_caps)
+        out.append({"frontier": FrontierBatch(unique, maps, n_unique, valid,
+                                              plan),
+                    "labels": labels_})
+    return out
+
+
+def _assert_bitwise_equal(got, want):
+    got_leaves, got_def = jax.tree.flatten(got)
+    want_leaves, want_def = jax.tree.flatten(want)
+    assert got_def == want_def
+    for a, b in zip(got_leaves, want_leaves):
+        a, b = np.asarray(a), np.asarray(b)
+        assert (a.dtype, a.shape) == (b.dtype, b.shape)
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("case", ["plan", "no_plan", "auto", "resume",
+                                  "stress16"])
+def test_pooled_sharded_source_bit_identical_to_serial_build(graph, case):
+    """The source samples its shards and builds the owner plan on the
+    process's thread pool; every leaf of every batch, the plan included, is
+    bit for bit the serial build's: over several steps, without a plan,
+    after the ``"auto"`` duplication peek, resumed through ``state_dict``
+    into a fresh source, and with 16 shards (more parts than pool threads)
+    under a shortened thread switch interval."""
+    import sys
+    n = 16 if case == "stress16" else N_SHARDS
+    owner_plan = {"no_plan": False, "auto": "auto"}.get(case, True)
+    interval = sys.getswitchinterval()
+    if case == "stress16":
+        sys.setswitchinterval(1e-6)
+    try:
+        src = _owner_source(graph, n_shards=n, owner_plan=owner_plan)
+        start = 0
+        if case == "resume":
+            src.next_batch()
+            src.next_batch()
+            snap = src.state_dict()
+            src = _owner_source(graph, n_shards=n)
+            src.load_state_dict(snap)
+            start = 2
+        got = [src.next_batch() for _ in range(3)]
+    finally:
+        sys.setswitchinterval(interval)
+    plan_caps = ((src.owner_cap, src.owner_unique_cap) if src.owner_plan
+                 else None)
+    want = _serial_batches(graph, n, 7, src.frontier_cap, plan_caps, 3,
+                           start=start)
+    if case == "auto":
+        # the peek measured the reference's own step-0 duplication, and it
+        # is past the threshold, so the peeked batch carries a plan
+        rows = sum(int(b["frontier"].n_unique)
+                   for b in _serial_batches(graph, n, 7, src.frontier_cap,
+                                            None, 1))
+        assert src.duplication_measured == src.frontier_cap * n / rows
+        assert src.owner_plan
+    assert (plan_caps is None) == (case == "no_plan")
+    assert all(b["frontier"].plan is not None for b in want) == src.owner_plan
+    for g, w in zip(got, want):
+        _assert_bitwise_equal(g, w)
+
+
+@pytest.mark.parametrize("caps", [dict(owner_cap=2), dict(owner_unique_cap=8)],
+                         ids=["bucket", "owned"])
+def test_pooled_owner_plan_overflow_counters_and_threads(graph, caps):
+    """With the pool, a bucket (first phase) or an owner's unique set
+    (second phase) over its cap still gives a batch with no plan, the
+    warning and one ``owner_plan_overflows``; the source reports its pool
+    threads and its shards' own sampling time; and 50 sources built and
+    dropped start no threads beyond the one shared pool."""
+    import threading
+
+    from repro.graph.engine import SHARD_POOL_WORKERS
+    src = _owner_source(graph, **caps)
+    with pytest.warns(UserWarning, match="owner plan overflow"):
+        batch = src.next_batch()
+    assert batch["frontier"].plan is None
+    stats = src.stats()
+    assert stats["owner_plan_overflows"] == 1 and stats["owned_rows"] == 0
+    assert stats["shard_workers"] > 1 and stats["shard_sample_us"] > 0
+
+    before = threading.active_count()
+    for seed in range(50):
+        _owner_source(graph, seed=seed).next_batch()
+    assert threading.active_count() <= before + SHARD_POOL_WORKERS
+    pool_threads = [t for t in threading.enumerate()
+                    if t.name.startswith("shard-pool")]
+    assert 0 < len(pool_threads) <= SHARD_POOL_WORKERS
 
 
 def test_owner_spec_field_roundtrip():
