@@ -1,12 +1,10 @@
-"""Shared benchmark utilities: timing, k-means + NMI (no sklearn offline),
-CSV row emission in the required ``name,us_per_call,derived`` format."""
+"""Shared benchmark utilities: k-means + NMI (no sklearn offline), CSV row
+emission in the required ``name,us_per_call,derived`` format."""
 
 from __future__ import annotations
 
-import time
-from typing import Callable, List, Optional, Tuple
+from typing import List, Tuple
 
-import jax
 import numpy as np
 
 ROWS: List[Tuple[str, float, str]] = []
@@ -37,30 +35,14 @@ def bench_entry(name: str, *, mode: str, dtype: str, **fields) -> dict:
     CPU semantics check — NOT a perf measurement) and the decode ``dtype``
     ("float32" / "bfloat16" / "int8"), so a number can never be read
     without the context that decides whether it means anything.  Writers
-    build entries through this helper; tools/ci.sh --bench asserts the keys
-    on the committed artifacts."""
+    build entries through this helper; tools/ci.sh --bench asserts dtype
+    and the quality columns on the committed BENCH_compression.json."""
     if mode not in BENCH_MODES:
         raise ValueError(f"bench entry {name!r}: mode must be one of "
                          f"{BENCH_MODES}, got {mode!r}")
     if not dtype or not isinstance(dtype, str):
         raise ValueError(f"bench entry {name!r}: missing dtype")
     return {"name": name, "mode": mode, "dtype": dtype, **fields}
-
-
-def time_fn(fn: Callable, *args, iters: int = 5, warmup: int = 2) -> float:
-    """Median wall-time per call in microseconds (blocks on outputs)."""
-    if SMOKE:
-        iters, warmup = 1, 1
-    for _ in range(warmup):
-        out = fn(*args)
-        jax.block_until_ready(out)
-    ts = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        out = fn(*args)
-        jax.block_until_ready(out)
-        ts.append(time.perf_counter() - t0)
-    return float(np.median(ts) * 1e6)
 
 
 # ---------------- tiny kmeans + NMI (paper §B.1.4 evaluation) --------------

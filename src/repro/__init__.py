@@ -13,7 +13,6 @@ optim     AdamW, schedules, gradient compression
 train     train-step factory, loop, checkpointing, fault tolerance
 serving   single-token decode engine
 parallel  logical-axis sharding rules, mesh helpers
-launch    production mesh, multi-pod dry-run, drivers, roofline
 configs   architecture registry (the 10 assigned archs + paper GNN stack)
 """
 

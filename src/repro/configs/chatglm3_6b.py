@@ -3,7 +3,7 @@ vocab=65024; RoPE applied to half the head dims ("2d" RoPE), QKV bias.
 [arXiv:2406.12793; hf]
 
 TP note: kv_heads=2 < model-axis 16 — the sharding resolver replicates KV
-heads (DESIGN.md §6), the standard fallback for narrow GQA under TP.
+heads, the standard fallback for narrow GQA under TP.
 """
 
 from repro.configs.base import EmbeddingSpec, LMConfig, register

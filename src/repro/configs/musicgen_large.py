@@ -7,7 +7,7 @@ the 4 codebook embeddings are summed (MusicGen's own input path); the head
 predicts 4x2048 logits per step.  Sinusoidal positions (no RoPE), LayerNorm
 + GELU per the original transformer recipe.
 
-Paper-technique note (DESIGN.md §4): vocab 2,048/codebook is tiny — the
+Paper-technique note: vocab 2,048/codebook is tiny — the
 hash-compressed table is LARGER than dense at paper hyper-params (ratio<1),
 so `dense` is the default; compressed kinds remain selectable for ablation.
 """

@@ -858,8 +858,7 @@ register_backend("tt", TTBackend)
 
 # ``auto`` prefers the owner-computes decode over the plain sharded decode
 # when the workload's measured duplication (frontier_rows / unique_rows, the
-# per-device decode work over the mean per-shard unique count — what
-# BENCH_shard.json reports) exceeds this: past 2x, the owner exchange
+# per-device decode work over the mean per-shard unique count) exceeds this: past 2x, the owner exchange
 # reclaims more decode rows than its two all_to_alls cost, and the default
 # owner_unique_cap = cap/2 sizing (graph.sampler.default_owner_caps) is
 # guaranteed adequate in expectation by the same inequality.

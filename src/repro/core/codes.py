@@ -5,7 +5,7 @@ is stored as ``n_bit = m * log2(c)`` bits.  Following the paper's example,
 each element is written MSB-first: ``[2, 0, 3, 1]`` with ``c=4`` becomes the
 bit string ``10 00 11 01``.
 
-TPU adaptation (DESIGN.md §3.2): bits are packed into 32-bit lanes
+TPU adaptation: bits are packed into 32-bit lanes
 (``uint32`` words, little-endian within a word: bit ``i`` of the code row
 lives in word ``i // 32`` at bit position ``i % 32``).  All conversions are
 vectorised shift/mask ops that fuse into the decode prologue.

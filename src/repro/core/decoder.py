@@ -7,7 +7,7 @@ codes (B, m) ints in [0, c)
      full  variant: no W0 (codebooks trainable)
   -> l-layer MLP with ReLU between linear layers: d_c -> d_m -> ... -> d_e
 
-TPU adaptation (DESIGN.md §3): the codebook retrieval + W0 scale is a
+TPU adaptation: the codebook retrieval + W0 scale is a
 ``repro.core.backend.DecodeBackend`` selected by ``lookup_impl`` ("gather" |
 "onehot" | "pallas" | "auto"); see that module for the implementations and
 the registration hook for new ones.

@@ -1,6 +1,6 @@
 """Closed-form memory / compression-ratio calculators (paper Tables 2, 4, 6).
 
-Validation discovery (recorded in EXPERIMENTS.md §Faithfulness): the paper's
+Validation discovery: the paper's
 *reported* numbers in Tables 2/4/6 correspond to a decoder whose MLP has two
 linear layers (d_c→d_m→d_e), i.e. the §3.2 formula with the ``(l−2)·d_m²``
 term equal to zero, while §B.2/§C.1 state l=3.  Both conventions are

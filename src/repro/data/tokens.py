@@ -1,4 +1,4 @@
-"""Synthetic LM token pipeline (offline container; DESIGN.md §7).
+"""Synthetic LM token pipeline (offline container).
 
 A latent Markov topic chain drives Zipf-distributed token emission, giving
 the stream real co-occurrence structure — which is exactly the auxiliary

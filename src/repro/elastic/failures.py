@@ -5,8 +5,7 @@ shard dies, *when* its heartbeats lag, *which* transfer chunk arrives
 corrupted — evaluated as pure predicates of ``(shard, step)`` /
 ``(seq, attempt)``.  Nothing here flips coins: the same plan against the
 same run produces the same failure sequence every time, which is what lets
-``tests/test_elastic.py`` assert bitwise post-recovery equality and
-``benchmarks/elastic_failover.py`` report reproducible recovery numbers.
+``tests/test_elastic.py`` assert bitwise post-recovery equality.
 
 The plan is consulted by ``ElasticManager`` (liveness at every step fence)
 and by ``transfer.transfer_state`` (chunk tampering on the simulated wire).

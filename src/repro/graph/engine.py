@@ -173,8 +173,8 @@ def default_frontier_cap(batch_size: int, fanouts, pad_to: int,
 
     Worst case is the *safe* default — an undersized cap raises mid-run —
     but real frontiers dedup far below it, so the stacked batch decodes
-    padding rows (see BENCH_shard.json rows-vs-unique columns).  Runs that
-    know their workload should pass a measured ``frontier_cap``."""
+    padding rows (``measure_duplication`` reports rows over unique).  Runs
+    that know their workload should pass a measured ``frontier_cap``."""
     worst = batch_size
     per_target = 1
     for f in fanouts:
@@ -383,11 +383,11 @@ class ShardedSageBatchSource:
         """Measured decode duplication of the upcoming batch:
         ``frontier_rows / unique_rows`` per device — the per-device decode
         work (``frontier_cap``, padding included) over the mean per-shard
-        unique count; exactly the ratio ``BENCH_shard.json`` reports and
-        the factor the owner decode can reclaim.  Peeks without consuming
-        (shard steps are restored, and the sampled parts are cached so the
-        next ``next_batch`` at the same step reuses instead of resampling),
-        so resume stays exact and the step-0 sampling cost is paid once."""
+        unique count; the factor the owner decode can reclaim.  Peeks
+        without consuming (shard steps are restored, and the sampled parts
+        are cached so the next ``next_batch`` at the same step reuses
+        instead of resampling), so resume stays exact and the step-0
+        sampling cost is paid once."""
         step0 = self.shards[0].step
         parts, _ = self._sample_shards()
         for s in self.shards:

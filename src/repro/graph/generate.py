@@ -1,4 +1,4 @@
-"""Synthetic graph / embedding generators (DESIGN.md §7).
+"""Synthetic graph / embedding generators.
 
 The container is offline, so OGB / GloVe / metapath2vec / transaction data
 are replaced with generators matching the statistics the paper's claims

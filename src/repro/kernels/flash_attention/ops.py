@@ -4,7 +4,7 @@ Public layout matches nn.attention: q (B, S, H, D), k/v (B, S, K, D).
 Forward: Pallas kernel (or the jnp oracle for unaligned shapes / CPU).
 Backward: XLA recompute (standard memory-saving trade: the bwd re-runs the
 reference attention under the residual-free recompute policy; a dedicated
-bwd kernel is a further optimisation recorded in EXPERIMENTS.md §Perf).
+bwd kernel is a further optimisation).
 """
 
 from __future__ import annotations

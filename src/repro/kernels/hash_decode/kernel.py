@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: fused compositional-code decode (DESIGN.md §3.1).
+"""Pallas TPU kernel: fused compositional-code decode.
 
 The decoder's codebook retrieval — on GPU a batch of ``m`` gathers — is
 re-expressed for the MXU as ``m`` one-hot × codebook matmuls accumulated in

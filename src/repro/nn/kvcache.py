@@ -49,7 +49,7 @@ class KVCache:
     def update(self, k_new: Array, v_new: Array) -> "KVCache":
         """Append S_new timesteps (B, S_new, n_kv, d_head) at ``pos``.
 
-        Sharding-aware write paths (EXPERIMENTS.md §Perf G6): a
+        Sharding-aware write paths: a
         dynamic_update_slice into a cache whose sequence (or head) dim is
         sharded makes GSPMD all-gather the WHOLE cache every decode step
         (measured: 11.5 GB/chip/step on zamba2 long_500k).  So:

@@ -65,7 +65,7 @@ def _topk_argmax(probs: Array, k: int):
     """top-k as k rounds of argmax+mask.  Equivalent to lax.top_k (up to tie
     order) but partitions trivially along the token dim — lax.top_k made
     GSPMD all-gather the full (T, E) router probs (measured 18 GB/chip/step
-    on granite train_4k; EXPERIMENTS.md §Perf H4c)."""
+    on granite train_4k)."""
     ws, ids = [], []
     p = probs
     for _ in range(k):
@@ -146,7 +146,7 @@ def _ep_local_ffn(x, w, idx, params_local, cfg: MoEConfig, e_local: int,
     dynamic_slice), as one DENSE matmul.  This keeps per-shard FLOPs at
     exactly cap_e·e_local·D·F on every backend — unlike ragged_dot, whose
     XLA:CPU reference lowering densifies over all groups (measured 16-38x
-    FLOP inflation on dbrx; EXPERIMENTS.md §Perf).
+    FLOP inflation on dbrx).
     """
     T = x.shape[0]
     k = cfg.top_k

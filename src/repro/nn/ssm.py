@@ -1,5 +1,5 @@
 """Mamba2 mixer via the chunked SSD (state-space duality) form
-(Dao & Gu, arXiv:2405.21060) — DESIGN.md §5.
+(Dao & Gu, arXiv:2405.21060).
 
 TPU adaptation: the chunked decomposition is already the MXU-native form —
 the intra-chunk term is a masked (L×L) matmul and the inter-chunk term is a
@@ -60,7 +60,7 @@ def init_ssm(key, cfg: SSMConfig) -> nn.Params:
     output dim aligns with SSD-head boundaries — the fused layout forced
     GSPMD to re-gather the (2·DI+2·N+H)-wide projection every layer because
     shard boundaries crossed the z/x/B/C/dt splits (measured 2.5x collective
-    reduction on zamba2/mamba2; EXPERIMENTS.md §Perf).  Depthwise conv over
+    reduction on zamba2/mamba2).  Depthwise conv over
     the concatenation == concatenation of depthwise convs, so semantics are
     identical to the fused form."""
     ks = nn.split_keys(key, ["z", "x", "b", "c", "dtp", "conv", "dt", "out"])

@@ -1,4 +1,4 @@
-"""Gradient compression for cross-pod data parallelism (DESIGN.md §6).
+"""Gradient compression for cross-pod data parallelism.
 
 int8 block-quantised all-reduce with error feedback (1-bit-Adam-family
 technique, adapted):

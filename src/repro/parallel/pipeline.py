@@ -1,4 +1,4 @@
-"""GPipe-style pipeline parallelism as a shard_map primitive (DESIGN.md §6).
+"""GPipe-style pipeline parallelism as a shard_map primitive.
 
 ``gpipe(stage_fn, stage_params, microbatches, mesh, axis)`` runs
 ``n_stages = mesh.shape[axis]`` pipeline stages, one per shard of ``axis``:
@@ -13,8 +13,8 @@ analytic, not hidden — report it alongside the roofline when using PP
 (the dry-run's per-chip FLOPs don't model idle ticks).
 
 Scope note: this is the PP building block (correctness-tested vs the
-sequential reference on a host mesh).  The production profiles in
-launch/profiles.py use TP/EP/DP — at the assigned shapes those dominated PP
+sequential reference on a host mesh).  The LM sharding policies use
+TP/EP/DP — at the assigned shapes those dominated PP
 in napkin math (16 stages on the model axis give a 48% bubble at 16
 microbatches); PP becomes the right tool at longer pipelines-per-pod or
 with interleaved schedules, both of which layer on top of this primitive.

@@ -1,6 +1,6 @@
 """Concrete sharding policies: params (TP ⊗ FSDP), caches, batches.
 
-Policy (DESIGN.md §6):
+Policy:
   * 2-D weights (stacked (L, D_in, D_out) or flat): TP-shard the
     "parallel" dim over ``model`` — column-parallel for in-projections
     (w_gate/w_up/wq/wk/wv/head), row-parallel for out-projections
@@ -8,7 +8,7 @@ Policy (DESIGN.md §6):
     XLA all-gathers per scan step and reduce-scatters grads).
   * attention weights only TP-shard when the *head count* divides the model
     axis (never split inside a head); granite (24H) and qwen2-vl (28H) fall
-    back to FSDP-only attention — documented in DESIGN.md.
+    back to FSDP-only attention.
   * MoE experts: E over ``model`` (EP ≡ TP axis), D over ``data``.
   * embedding: dense table vocab-parallel; compressed codes + decoder
     replicated (the decoder is ≤ 10 MB — that IS the paper's point).
@@ -39,7 +39,7 @@ class Strategy:
       ``model`` axis for the respective weights + activations.
     dp_over_model: fold the model axis into data parallelism (batch shards
       over pod×data×model) — the right call for small models where TP
-      all-reduces dominate (e.g. qwen1.5-0.5b; see EXPERIMENTS.md §Perf).
+      all-reduces dominate (e.g. qwen1.5-0.5b).
     fsdp: ZeRO-style parameter/optimizer sharding over the data axis.
     seq_shard_activations: sequence-shard the residual stream over `model`
       between blocks (Megatron sequence parallelism; pairs with tp).

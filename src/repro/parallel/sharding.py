@@ -1,4 +1,4 @@
-"""Logical-axis sharding rules (DESIGN.md §6).
+"""Logical-axis sharding rules.
 
 Model code annotates tensors with *logical* axis names
 (``logical(x, "batch", "seq", "embed")``); the active ``ShardingRules`` maps

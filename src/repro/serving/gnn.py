@@ -12,8 +12,8 @@ between requests, so a decoded embedding never goes stale.  The engine
 therefore keeps a device-resident ``CacheState`` across requests and
 partitions every frontier host-side (``CachedDecodeBackend.plan_missonly``)
 into a padded miss-prefix — **only cache misses enter the decoder**, and
-``rows_decoded`` (vs the full frontier row count) is the measured win
-(``benchmarks/serving_gnn.py``, ``BENCH_decode.json``).
+``rows_decoded`` (vs the full frontier row count) is the win, asserted in
+``tests/test_runtime.py``.
 
 Cross-request dedup (``serve_many``, ISSUE 7): a microbatch of concurrent
 requests — coalesced by ``serving.batcher.ServingBatcher`` — concatenates

@@ -1,4 +1,4 @@
-"""Checkpointing for fault tolerance + elastic restarts (DESIGN.md §6).
+"""Checkpointing for fault tolerance + elastic restarts.
 
 Design points (1000+-node posture):
   * **atomic**: write to ``step_XXXX.tmp`` then ``os.replace`` — a crash

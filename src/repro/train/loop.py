@@ -1,6 +1,6 @@
 """Training loop with fault tolerance + straggler monitoring.
 
-Responsibilities (DESIGN.md §6):
+Responsibilities:
   * auto-resume: on start, restore the newest valid checkpoint (params,
     optimizer, step counter, data-pipeline state) and continue — the
     restart path after a node failure.

@@ -351,6 +351,40 @@ def test_host_params_carry_no_codes_buf(graph):
         host.close()
 
 
+def test_host_code_bytes_do_not_grow_with_the_graph():
+    """At one batch and ``frontier_cap``, host placement keeps no
+    ``codes_buf`` on the device and streams the same code bytes per batch
+    on a 2,000- and an 8,000-node graph; the replicated buffer of device
+    placement grows with the graph."""
+    resident = {}
+    streamed = {}
+    for n in (2_000, 8_000):
+        base = paper_gnn_config("sage", n_nodes=n, n_classes=8, fanout=5)
+        for placement in ("device", "host"):
+            model = dataclasses.replace(base, embedding=dataclasses.replace(
+                base.embedding, c=16, m=8, d_c=64, d_m=64,
+                lookup_impl="gather", codes_placement=placement))
+            spec = RuntimeSpec(
+                graph=GraphSource(kind="powerlaw", seed=0, n_nodes=n,
+                                  n_classes=8, avg_degree=8),
+                model=model, optimizer=OPT, batch_size=32, pad_to=64,
+                frontier_cap=1024, prefetch_depth=2)
+            rt = GraphRuntime.from_spec(spec)
+            try:
+                resident[n, placement] = _param_codes_buf_bytes(
+                    rt.state["params"])
+                for _ in range(2):
+                    rt.data_iter.next_batch()
+                streamed[n, placement] = rt.data_iter.stats()[
+                    "transferred_code_bytes_per_batch"]
+            finally:
+                rt.close()
+    assert resident[2_000, "host"] == resident[8_000, "host"] == 0
+    assert streamed[2_000, "host"] == streamed[8_000, "host"] > 0
+    assert 0 < resident[2_000, "device"] < resident[8_000, "device"]
+    assert streamed[2_000, "device"] == streamed[8_000, "device"] == 0
+
+
 def test_unknown_placement_fails_at_init():
     ecfg = _cfg(codes_placement="hbm").embedding_config()
     with pytest.raises(ValueError, match="codes_placement"):

@@ -275,6 +275,15 @@ def test_quantize_codebooks_roundtrip_bound():
     np.testing.assert_array_equal(np.asarray(g), np.ones_like(np.asarray(cb)))
 
 
+def test_int8_codebooks_take_a_quarter_of_the_bytes():
+    """At the paper's widths (m=16, c=256, d_c=512) int8 codebooks plus
+    their per-code f32 scales hold at most 1/3.5 of the f32 bytes."""
+    cb = jnp.zeros((16, 256, 512), jnp.float32)
+    q, scales = hd_ops.quantize_codebooks(cb)
+    assert q.dtype == jnp.int8 and scales.shape == (16, 256)
+    assert (q.nbytes + scales.nbytes) * 3.5 <= cb.nbytes
+
+
 # ---------------- lsh_encode ----------------
 
 @pytest.mark.parametrize("n,d,w", [(2048, 512, 32), (1024, 256, 16), (512, 128, 32)])

@@ -50,7 +50,7 @@ def test_ratio_grows_with_entities():
 
 
 def test_musicgen_marginality_note():
-    """DESIGN.md §4: at n=2048/codebook the gain is marginal (~1.2x, vs the
+    """At n=2048/codebook the gain is marginal (~1.2x, vs the
     paper's ~40x at products scale) — compression not worth the lossiness
     for a 16 MB table, hence musicgen defaults to dense."""
     r = M.compression_ratio(2048, 2048, 256, 16)

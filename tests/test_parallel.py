@@ -96,7 +96,7 @@ def test_dp_over_model_strategy_matches():
 
 def test_elastic_restart_different_mesh():
     """Checkpoint on mesh (4,2), restore + continue on mesh (2,2) with 4
-    devices — elastic-scaling restart (DESIGN.md §6)."""
+    devices — elastic-scaling restart."""
     run_devices("""
         import os, tempfile
         import jax, jax.numpy as jnp, numpy as np

@@ -576,7 +576,7 @@ def test_owner_spec_field_roundtrip():
 
 def test_owner_caps_default_sizing():
     from repro.graph.sampler import default_owner_caps
-    # the BENCH_shard.json workload: cap·1.25/n request slots, cap/2 decode
+    # a 4-shard, cap-7168 workload: cap·1.25/n request slots, cap/2 decode
     # rows (the duplication-threshold inequality, both sublane-rounded)
     assert default_owner_caps(7168, 4) == (2240, 3584)
     # never exceed the trivially safe bounds (cap, n_shards·owner_cap)
